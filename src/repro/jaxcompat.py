@@ -1,74 +1,29 @@
-"""Version-bridging helpers for the jax sharding API.
-
-The sharding surface moved fast across jax releases: ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``AxisType``, and the two-argument
-``AbstractMesh(sizes, names)`` constructor only exist on newer versions,
-while older releases spell the same concepts as the legacy mesh context
-manager and resource env.  All repro code goes through this module so a
-single environment's jax pins don't decide whether the suite collects.
-"""
+"""Thin helpers over the jax sharding API, so call sites stay short."""
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 
 
 def get_abstract_mesh() -> Optional[object]:
-    """The ambient mesh sharding constraints should resolve against.
-
-    Returns an object with ``.axis_names`` / ``.shape`` or ``None`` when
-    no mesh context is active.  Newer jax tracks this via
-    ``jax.sharding.get_abstract_mesh``; older releases via the abstract
-    mesh config slot or the legacy physical-mesh resource env (entered
-    by ``with mesh:`` — which is exactly what :func:`set_mesh` falls
-    back to there).
-    """
-    modern = getattr(jax.sharding, "get_abstract_mesh", None)
-    if modern is not None:
-        return modern()
-    from jax._src import mesh as _mesh
-
-    am = _mesh.get_abstract_mesh()
-    if am is not None and getattr(am, "axis_names", ()):
-        return am
-    pm = _mesh.thread_resources.env.physical_mesh
-    if pm.axis_names:
-        return pm
-    return None
+    """The ambient mesh sharding constraints should resolve against."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient for jit'd sharding.
-
-    ``jax.set_mesh(mesh)`` when available; otherwise the legacy
-    ``with mesh:`` resource-env context (``Mesh`` is its own context
-    manager there, and :func:`get_abstract_mesh` reads it back).
-    """
-    modern = getattr(jax, "set_mesh", None)
-    if modern is not None:
-        return modern(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient for jit'd sharding."""
+    return jax.set_mesh(mesh)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with auto axis types where that concept exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
     """Device-less mesh for planning shardings without real hardware."""
-    sizes: Tuple[int, ...] = tuple(axis_shapes)
-    names: Tuple[str, ...] = tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:
-        # older signature: one tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: masked gather-regrid for polar->Cartesian gridding.
+"""Pallas TPU kernel: masked weighted regrid for polar->Cartesian gridding.
 
 The gridding hot loop turns a (time, azimuth, range) moment block into a
 (time, cells) Cartesian product through a precomputed gate map: for each
@@ -6,21 +6,23 @@ output cell, at most ``k`` contributing gates (flat indices into the
 flattened gate axis) with their weights (``repro.radar.grid.GridMapping``
 builds the map once per site geometry x grid and caches it).
 
-Layout: the gate axis stays whole in VMEM — a regrid needs arbitrary
-gates, so tiling it would turn one gather into a scatter across grid
-steps — while time and cells tile as ``(T/bt, C/bc)``.  The per-cell
-gather is a ``take_along_axis`` over the flattened gate axis (VMEM-local,
-no HBM indirection), and the masked weighted mean mirrors
+Split: the gather runs in XLA, outside the kernel — one ``jnp.take`` over
+the flat gate axis, neighbour-major, so the gathered block is
+``(k, T, C)`` with cells on the lanes.  TPU vector memory cannot hold an
+arbitrary gate axis (a 14-cut full-geometry CAPPI stack is 48 MB per
+scan) and Mosaic has no general in-kernel gather, while XLA's gather
+reads HBM directly.  The kernel then does the masked weighted mean over
+the ``k`` neighbours on aligned ``(k, bt, bc)`` tiles: a reduction over
+the leading axis, i.e. plain vector adds.  A NaN gate drops out of its
+cell's mean instead of poisoning it, and the per-cell math mirrors
 :func:`repro.kernels.ref.grid_map` operation-for-operation so interpret
 mode matches the oracle bitwise.
 
-VMEM per step (defaults bt=4, bc=1024, k=4, G=720*1192):
-4*G*4B ≈ 13.1 MB field + 2 * 1024*4*4B gather map ≈ 13.2 MB.  ``bt`` is
-auto-clamped so the field block stays inside ``FIELD_VMEM_BUDGET``; a
-gate axis too large for even one time row (e.g. a many-sweep CAPPI
-stack on full NEXRAD geometry) is rejected with a clear error on the
-compiled path rather than failing inside Mosaic — grid such products
-per sweep, or on a coarser grid.
+Grid: ``(cdiv(T, bt), cdiv(C, bc))`` with tiles from
+:func:`repro.kernels._tiling.tile` under one VMEM budget: cells take the
+whole axis or a multiple of 128, time the whole axis or a multiple of 8.
+The weights travel as ``(k, 1, C)`` so their block tail ``(1, bc)`` is
+legal for any ``bc``.
 """
 
 from __future__ import annotations
@@ -31,76 +33,54 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# field-block budget: roughly half of a TPU core's ~16 MB VMEM, leaving
-# room for the gather map, the output block and double buffering
-FIELD_VMEM_BUDGET = 8 * 1024 * 1024
+from ._tiling import LANE, SUBLANE, VMEM_BUDGET, round_up, tile
 
 
-def _grid_map_kernel(field_ref, idx_ref, w_ref, out_ref):
-    f = field_ref[...]                      # (bt, G) float32
-    idx = idx_ref[...]                      # (bc, k) int32
-    w = w_ref[...]                          # (bc, k) float32
-    bt = f.shape[0]
-    flat = idx.reshape(-1)                  # (bc*k,)
-    gathered = jnp.take_along_axis(
-        f, jnp.broadcast_to(flat[None, :], (bt, flat.shape[0])), axis=1
-    )
-    vals = gathered.reshape(bt, *idx.shape)  # (bt, bc, k)
-    valid = jnp.isfinite(vals) & (w > 0.0)[None, :, :]
-    wv = jnp.where(valid, w[None, :, :], 0.0)
-    num = jnp.sum(jnp.where(valid, vals, 0.0) * wv, axis=-1)
-    den = jnp.sum(wv, axis=-1)
+def _grid_map_kernel(vals_ref, w_ref, out_ref):
+    vals = vals_ref[...]                    # (k, bt, bc) gathered gates
+    w = w_ref[...]                          # (k, 1, bc) float32
+    valid = jnp.isfinite(vals) & (w > 0.0)
+    wv = jnp.where(valid, w, 0.0)
+    num = jnp.sum(jnp.where(valid, vals, 0.0) * wv, axis=0)
+    den = jnp.sum(wv, axis=0)
     out_ref[...] = jnp.where(den > 0.0, num / jnp.maximum(den, 1e-12),
                              jnp.nan)
 
 
-@functools.partial(jax.jit, static_argnames=("bt", "bc", "interpret"))
+@functools.partial(jax.jit, static_argnames=("vmem_budget", "interpret"))
 def grid_map_pallas(
     field: jax.Array,                      # (T, G) float32, G = az*range
     gate_idx: jax.Array,                   # (C, k) int32 into [0, G)
     weights: jax.Array,                    # (C, k) float32, <= 0 = no gate
     *,
-    bt: int = 4,
-    bc: int = 1024,
+    vmem_budget: int = VMEM_BUDGET,
     interpret: bool = False,
 ) -> jax.Array:
-    """Pallas gather-accumulate kernel mapping polar gates to grid cells."""
-    T, G = field.shape
+    """Gather in XLA, then the Pallas masked weighted mean per cell."""
+    T, _G = field.shape
     C, k = gate_idx.shape
     if T == 0 or C == 0:
         # degenerate axes (an empty planner window): same answer as the
         # oracle, without tiling a zero-extent grid
         return jnp.full((T, C), jnp.nan, jnp.float32)
-    # the gate axis stays whole per step: clamp the time tile to budget
-    bt = max(1, min(bt, T, FIELD_VMEM_BUDGET // (G * 4)))
-    if not interpret and G * 4 > FIELD_VMEM_BUDGET:
-        raise ValueError(
-            f"gate axis of {G} gates needs {G * 4 / 2**20:.0f} MB VMEM "
-            "per time row — beyond the field budget; grid per sweep or "
-            "coarsen the stack (interpret mode has no such limit)"
-        )
-    bc = min(bc, C)
-    Tp = -(-T // bt) * bt
-    Cp = -(-C // bc) * bc
-    if Tp != T:
-        # NaN rows are masked out by construction; sliced off below
-        field = jnp.pad(field, ((0, Tp - T), (0, 0)),
-                        constant_values=jnp.nan)
-    if Cp != C:
-        # padded cells gather gate 0 with weight 0 -> NaN, sliced off below
-        gate_idx = jnp.pad(gate_idx, ((0, Cp - C), (0, 0)))
-        weights = jnp.pad(weights, ((0, Cp - C), (0, 0)))
-    out = pl.pallas_call(
+    vals = jnp.take(field.astype(jnp.float32),
+                    gate_idx.T.reshape(-1).astype(jnp.int32), axis=1)
+    vals = vals.reshape(T, k, C).transpose(1, 0, 2)          # (k, T, C)
+    w = weights.astype(jnp.float32).T.reshape(k, 1, C)
+    # bytes per cell at <= 8 time rows (k gathered + k weight + 1 output
+    # sublane tiles, double-buffered), then per time row at bc cells
+    bc = tile(C, LANE, 2 * (2 * k + 1) * SUBLANE * 4, vmem_budget)
+    lanes = round_up(bc, LANE)
+    bt = tile(T, SUBLANE, 2 * (k + 1) * lanes * 4,
+              vmem_budget - 2 * k * SUBLANE * lanes * 4)
+    return pl.pallas_call(
         _grid_map_kernel,
-        out_shape=jax.ShapeDtypeStruct((Tp, Cp), jnp.float32),
-        grid=(Tp // bt, Cp // bc),
+        out_shape=jax.ShapeDtypeStruct((T, C), jnp.float32),
+        grid=(pl.cdiv(T, bt), pl.cdiv(C, bc)),
         in_specs=[
-            pl.BlockSpec((bt, G), lambda i, j: (i, 0)),
-            pl.BlockSpec((bc, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((bc, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((k, bt, bc), lambda i, j: (0, i, j)),
+            pl.BlockSpec((k, 1, bc), lambda i, j: (0, 0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bc), lambda i, j: (i, j)),
         interpret=interpret,
-    )(field.astype(jnp.float32), gate_idx.astype(jnp.int32),
-      weights.astype(jnp.float32))
-    return out[:T, :C]
+    )(vals, w)

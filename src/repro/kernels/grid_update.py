@@ -9,16 +9,16 @@ them into the full ``(time, cells)`` state instead of a full regrid.
 TPU has no efficient scatter, so the patch is phrased as its inverse
 gather: each output cell reads its update column through a precomputed
 ``pos`` map (``-1`` marks untouched cells, which pass their state
-through bitwise).  Layout mirrors :mod:`repro.kernels.grid_map`: the
-compact update axis stays whole in VMEM — a cell anywhere on the grid
-may read any update column — while time and cells tile as
-``(T/bt, C/bc)``.  The combine (`set`/`add`/NaN-aware `max`) mirrors
-:func:`repro.kernels.ref.grid_update` operation-for-operation so
-interpret mode matches the oracle bitwise.
+through bitwise).  The gather runs in XLA, outside the kernel (one
+``jnp.take`` over the update axis, which may be far larger than vector
+memory); the kernel does the combine — `set`/`add`/NaN-aware `max` —
+on aligned ``(bt, bc)`` tiles of state, gathered values and ``pos``,
+mirroring :func:`repro.kernels.ref.grid_update` operation-for-operation
+so interpret mode matches the oracle bitwise.
 
-VMEM per step (defaults bt=8, bc=1024, M touched cells): ``bt*M*4`` B of
-update block + two ``(bt, bc)`` tiles; ``bt`` is auto-clamped so the
-update block stays inside ``UPD_VMEM_BUDGET``.
+Grid: ``(cdiv(T, bt), cdiv(C, bc))`` with tiles from
+:func:`repro.kernels._tiling.tile` under one VMEM budget: cells take the
+whole axis or a multiple of 128, time the whole axis or a multiple of 8.
 """
 
 from __future__ import annotations
@@ -29,44 +29,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# update-block budget: roughly half of a TPU core's ~16 MB VMEM, leaving
-# room for the state/output tiles, the pos map and double buffering
-UPD_VMEM_BUDGET = 8 * 1024 * 1024
+from ._tiling import LANE, SUBLANE, VMEM_BUDGET, round_up, tile
 
 _OPS = ("set", "add", "max")
 
 
-def _grid_update_kernel(state_ref, upd_ref, pos_ref, out_ref, *, op):
+def _grid_update_kernel(state_ref, vals_ref, pos_ref, out_ref, *, op):
     s = state_ref[...]                      # (bt, bc) float32
-    u = upd_ref[...]                        # (bt, M) float32
-    p = pos_ref[...].reshape(-1)            # (bc,) int32
-    touched = p >= 0
-    safe = jnp.where(touched, p, 0)
-    vals = jnp.take_along_axis(
-        u, jnp.broadcast_to(safe[None, :], (s.shape[0], safe.shape[0])),
-        axis=1,
-    )                                       # (bt, bc)
+    vals = vals_ref[...]                    # (bt, bc) gathered updates
+    touched = pos_ref[...] >= 0             # (1, bc)
     if op == "set":
         new = vals
     elif op == "add":
         new = s + vals
     else:
         new = jnp.fmax(s, vals)
-    out_ref[...] = jnp.where(touched[None, :], new, s)
+    out_ref[...] = jnp.where(touched, new, s)
 
 
-@functools.partial(jax.jit, static_argnames=("op", "bt", "bc", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("op", "vmem_budget", "interpret"))
 def grid_update_pallas(
     state: jax.Array,                      # (T, C) float32 product state
     upd: jax.Array,                        # (T, M) float32 update block
     pos: jax.Array,                        # (C,) int32 into [0, M), -1 = keep
     *,
     op: str = "set",
-    bt: int = 8,
-    bc: int = 1024,
+    vmem_budget: int = VMEM_BUDGET,
     interpret: bool = False,
 ) -> jax.Array:
-    """Pallas inverse-scatter kernel patching touched grid cells."""
+    """Gather in XLA, then the Pallas combine patching touched cells."""
     if op not in _OPS:
         raise ValueError(f"unknown grid_update op {op!r} (set|add|max)")
     T, C = state.shape
@@ -75,37 +67,24 @@ def grid_update_pallas(
         # nothing to patch (or nothing to patch into): the state is the
         # answer, same as the oracle, without tiling a zero-extent grid
         return state.astype(jnp.float32)
-    # the update axis stays whole per step: clamp the time tile to budget
-    bt = max(1, min(bt, T, UPD_VMEM_BUDGET // (M * 4)))
-    if not interpret and M * 4 > UPD_VMEM_BUDGET:
-        raise ValueError(
-            f"update block of {M} cells needs {M * 4 / 2**20:.0f} MB VMEM "
-            "per time row — beyond the budget; patch in cell batches "
-            "(interpret mode has no such limit)"
-        )
-    bc = min(bc, C)
-    Tp = -(-T // bt) * bt
-    Cp = -(-C // bc) * bc
-    if Tp != T:
-        # padded time rows read padded updates; sliced off below
-        state = jnp.pad(state, ((0, Tp - T), (0, 0)))
-        upd = jnp.pad(upd, ((0, Tp - T), (0, 0)))
-    if Cp != C:
-        # padded cells are marked untouched (-1): state (zero) passes
-        # through and is sliced off below
-        state = jnp.pad(state, ((0, 0), (0, Cp - C)))
-        pos = jnp.pad(pos, (0, Cp - C), constant_values=-1)
-    out = pl.pallas_call(
+    pos = pos.astype(jnp.int32)
+    vals = jnp.take(upd.astype(jnp.float32), jnp.where(pos >= 0, pos, 0),
+                    axis=1)                                  # (T, C)
+    # bytes per cell at <= 8 time rows (state, values, output and pos
+    # sublane tiles, double-buffered), then per time row at bc cells
+    bc = tile(C, LANE, 2 * 4 * SUBLANE * 4, vmem_budget)
+    lanes = round_up(bc, LANE)
+    bt = tile(T, SUBLANE, 2 * 3 * lanes * 4,
+              vmem_budget - 2 * SUBLANE * lanes * 4)
+    return pl.pallas_call(
         functools.partial(_grid_update_kernel, op=op),
-        out_shape=jax.ShapeDtypeStruct((Tp, Cp), jnp.float32),
-        grid=(Tp // bt, Cp // bc),
+        out_shape=jax.ShapeDtypeStruct((T, C), jnp.float32),
+        grid=(pl.cdiv(T, bt), pl.cdiv(C, bc)),
         in_specs=[
             pl.BlockSpec((bt, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bt, M), lambda i, j: (i, 0)),
-            pl.BlockSpec((bc, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((bt, bc), lambda i, j: (i, j)),
+            pl.BlockSpec((1, bc), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bc), lambda i, j: (i, j)),
         interpret=interpret,
-    )(state.astype(jnp.float32), upd.astype(jnp.float32),
-      pos.astype(jnp.int32).reshape(-1, 1))
-    return out[:T, :C]
+    )(state.astype(jnp.float32), vals, pos.reshape(1, C))

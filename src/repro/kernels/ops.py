@@ -64,16 +64,13 @@ def grid_map(
     gate_idx: jax.Array,       # (cells, k) int32
     weights: jax.Array,        # (cells, k) float32
     *,
-    bt: int = 4,
-    bc: int = 1024,
     mode: str = "auto",
 ) -> jax.Array:
     """Polar-to-grid gather-accumulate (kernel or reference)."""
     use_kernel, interpret = _resolve(mode)
     if not use_kernel:
         return ref.grid_map(field, gate_idx, weights)
-    return grid_map_pallas(field, gate_idx, weights, bt=bt, bc=bc,
-                           interpret=interpret)
+    return grid_map_pallas(field, gate_idx, weights, interpret=interpret)
 
 
 def grid_update(
@@ -82,16 +79,13 @@ def grid_update(
     pos: jax.Array,            # (cells,) int32, -1 = untouched
     *,
     op: str = "set",
-    bt: int = 8,
-    bc: int = 1024,
     mode: str = "auto",
 ) -> jax.Array:
     """Incremental scatter-update of a gridded product (kernel or ref)."""
     use_kernel, interpret = _resolve(mode)
     if not use_kernel:
         return ref.grid_update(state, upd, pos, op=op)
-    return grid_update_pallas(state, upd, pos, op=op, bt=bt, bc=bc,
-                              interpret=interpret)
+    return grid_update_pallas(state, upd, pos, op=op, interpret=interpret)
 
 
 def zr_accum(

@@ -1,9 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the semantics of record: each kernel in this package must match
-its oracle to float tolerance under ``interpret=True`` (see
-``tests/test_kernels.py``).  They are also the CPU execution path — on the
-CPU container the ops dispatch here, on TPU they dispatch to the kernels.
+its oracle under ``interpret=True`` — the radar kernels bitwise, against
+the oracle compiled by ``jax.jit`` (see ``tests/test_kernels.py``).  They
+are also the CPU execution path: with ``mode="auto"`` the ops dispatch
+here on a CPU backend and to the kernels on a TPU.
 """
 
 from __future__ import annotations

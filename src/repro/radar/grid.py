@@ -33,7 +33,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..kernels import ops
-from ..store import Session
+from ..store import NotFound, Session
 from . import geometry
 from ._selection import TimeSliceLike, as_time_slice
 
@@ -418,7 +418,7 @@ def _discover_sweeps(session: Session, vcp: str) -> List[int]:
             except ValueError:
                 continue
     if not out:
-        raise ValueError(f"no sweeps under {vcp!r}")
+        raise NotFound(f"no sweeps under {vcp!r}")
     return sorted(out)
 
 

@@ -561,9 +561,10 @@ class ArchiveService:
         session = self.session(tenant, clean["repo"])
         try:
             return compute_product(session, req)
-        except ApiError:
-            raise
-        except Exception as exc:
+        except KeyError as exc:
+            # the store's NotFound is a KeyError: a missing VCP, sweep or
+            # moment.  Anything else (a kernel or compile failure) is the
+            # server's fault and goes out as a 500.
             raise ApiError(
                 404, f"product inputs not found: "
                      f"{type(exc).__name__}: {exc}") from None

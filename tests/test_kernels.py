@@ -1,8 +1,13 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles.
 
-Every Pallas kernel is swept over shapes/dtypes (hypothesis) and asserted
-allclose against ``repro.kernels.ref`` — the contract the system relies on
-when it dispatches kernels on TPU.
+Every Pallas kernel is swept over shapes/dtypes (hypothesis) and checked
+against ``repro.kernels.ref`` — the contract the system relies on when it
+dispatches kernels on TPU.  The radar kernels must match bitwise; the
+sweeps pass a small ``vmem_budget`` so that the shape-derived tiles split
+every axis into several (ragged) grid steps.  The oracle runs under
+``jax.jit``, as one XLA program like the interpreted kernel body: eager
+op-by-op dispatch rounds the fused transcendental and reduction steps
+differently in the last bit.
 """
 
 import jax
@@ -41,9 +46,10 @@ def test_qvp_reduce_matches_ref(t, a, r, seed):
     rng = np.random.default_rng(seed)
     field = _radar_field(rng, t, a, r)
     quality = rng.uniform(0.5, 1.0, size=(t, a, r)).astype(np.float32)
-    got = qvp_reduce_pallas(field, quality, bt=4, br=128, interpret=True)
-    want = ref.qvp_reduce(field, quality)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    got = qvp_reduce_pallas(field, quality, vmem_budget=64 * 1024,
+                            interpret=True)
+    want = jax.jit(ref.qvp_reduce)(field, quality)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_qvp_reduce_no_quality_path():
@@ -82,7 +88,7 @@ def test_grid_map_matches_ref_bitwise(t, g, c, k, seed):
     idx = rng.integers(0, g, size=(c, k)).astype(np.int32)
     w = rng.uniform(0.0, 2.0, size=(c, k)).astype(np.float32)
     w[rng.random((c, k)) < 0.3] = 0.0     # dropped neighbours
-    got = np.asarray(grid_map_pallas(field, idx, w, bt=4, bc=256,
+    got = np.asarray(grid_map_pallas(field, idx, w, vmem_budget=128 * 1024,
                                      interpret=True))
     want = np.asarray(ref.grid_map(field, idx, w))
     np.testing.assert_array_equal(got, want)
@@ -160,8 +166,9 @@ def test_grid_update_matches_ref_bitwise(t, c, seed, op, touched_frac):
     pos[touched] = rng.permutation(m).astype(np.int32)
     upd = rng.normal(20.0, 12.0, size=(t, m)).astype(np.float32)
     upd[rng.random((t, m)) < 0.2] = np.nan
-    got = np.asarray(grid_update_pallas(state, upd, pos, op=op, bt=4,
-                                        bc=256, interpret=True))
+    got = np.asarray(grid_update_pallas(state, upd, pos, op=op,
+                                        vmem_budget=128 * 1024,
+                                        interpret=True))
     want = np.asarray(ref.grid_update(state, upd, pos, op=op))
     np.testing.assert_array_equal(got, want)
 
@@ -233,9 +240,21 @@ def test_zr_accum_matches_ref(t, a, r, seed):
     rng = np.random.default_rng(seed)
     dbz = _radar_field(rng, t, a, r)
     dt_s = rng.uniform(200.0, 400.0, size=(t,)).astype(np.float32)
-    got = zr_accum_pallas(dbz, dt_s, bt=4, ba=16, br=128, interpret=True)
+    got = zr_accum_pallas(dbz, dt_s, vmem_budget=256 * 1024, interpret=True)
+    want = jax.jit(ref.zr_accum)(dbz, dt_s)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("t", [7, 12])
+def test_zr_accum_window_longer_than_time_tile(t):
+    """A window that does not fit one time tile is padded (NaN dBZ, zero
+    weight) and summed tile by tile: only the summation order changes."""
+    rng = np.random.default_rng(t)
+    dbz = _radar_field(rng, t, 20, 150)
+    dt_s = rng.uniform(200.0, 400.0, size=(t,)).astype(np.float32)
+    got = zr_accum_pallas(dbz, dt_s, vmem_budget=64 * 1024, interpret=True)
     want = ref.zr_accum(dbz, dt_s)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
 def test_zr_accum_zero_below_threshold():

@@ -356,6 +356,39 @@ def test_unknown_things_are_404(server, path):
     assert b"error" in body
 
 
+@pytest.mark.parametrize("path", [
+    f"/products/qvp?repo=KVNX&vcp={VCP}&sweep=9",
+    f"/products/qpe?repo=KVNX&vcp={VCP}&moment=NOPE",
+    "/products/cappi?repo=KVNX&vcp=VCP-999",
+])
+def test_missing_product_inputs_are_404(server, path):
+    status, _h, body = _get(server, path)
+    assert status == 404, (path, body)
+    assert b"product inputs not found" in body
+
+
+def test_kernel_failure_is_500_not_404(archive, monkeypatch):
+    """A failure inside the product computation (a kernel that does not
+    compile, a device out of memory) is the server's fault: it must not
+    reach the client disguised as a missing input."""
+    import repro.serve.http as http_mod
+
+    def broken(*_a, **_k):
+        raise ValueError("Mosaic failed to compile the kernel")
+
+    monkeypatch.setattr(http_mod, "compute_product", broken)
+    catalog, _repos = archive
+    service = ArchiveService(catalog)
+    try:
+        with ArchiveServer(service) as srv:
+            status, _h, body = _get(
+                srv, f"/products/qvp?repo=KVNX&vcp={VCP}&sweep=0")
+    finally:
+        service.close()
+    assert status == 500, body
+    assert b"Mosaic failed to compile" in body
+
+
 def test_bad_tenant_is_400(server):
     status, _h, body = _get(server, "/catalog",
                             headers={"X-Tenant": "bad tenant!"})
