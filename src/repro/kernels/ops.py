@@ -5,14 +5,21 @@
   * ``"kernel"`` — force the Pallas kernel (interpret=True off-TPU, which
                    is how the CPU CI validates kernel semantics)
   * ``"ref"``    — force the reference implementation
+
+:func:`to_host` is how product code calls a kernel and takes its result
+back to the host, timed as the spans ``dispatch.call`` and
+``dispatch.wait``.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro import obs
 
 from . import ref
 from .flash_attention import flash_attention_pallas
@@ -36,6 +43,22 @@ def _resolve(mode: str) -> Tuple[bool, bool]:
     if mode == "auto":
         return _on_tpu(), False
     raise ValueError(f"unknown mode {mode!r}")
+
+
+def to_host(fn: Callable[..., Any], *args: Any, **kwargs: Any) -> np.ndarray:
+    """Call the kernel wrapper ``fn`` and return its result on the host.
+
+    The call (argument conversion, staging the host arrays, whose bytes
+    it counts, for their copy to the device, and the enqueue) is the span
+    ``dispatch.call``; blocking until the result is on the host (copies
+    still in flight, the kernel, the copy back) is ``dispatch.wait``.
+    Both carry the kernel's name."""
+    kernel = fn.__name__
+    host = sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+    with obs.span("dispatch.call", nbytes=host, kernel=kernel):
+        result = fn(*args, **kwargs)
+    with obs.span("dispatch.wait", kernel=kernel):
+        return np.asarray(result)
 
 
 def qvp_reduce(

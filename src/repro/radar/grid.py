@@ -508,9 +508,10 @@ def grid_sweep_from_session(
                       (f"{vcp}/sweep_{sweep}/{moment}", (tsl,))], wait=False)
     times = session.array(f"{vcp}/time")[tsl]
     block = session.array(f"{vcp}/sweep_{sweep}/{moment}")[tsl]
-    out = np.asarray(ops.grid_map(
-        _flat_gates(block), mapping.gate_idx, mapping.weights, mode=mode,
-    )).reshape(-1, grid.ny, grid.nx)
+    out = ops.to_host(
+        ops.grid_map, _flat_gates(block), mapping.gate_idx, mapping.weights,
+        mode=mode,
+    ).reshape(-1, grid.ny, grid.nx)
     return GridProduct(
         out, np.asarray(times), grid, moment, "ppi",
         {"vcp": vcp, "sweep": int(sweep), "elevation_deg": elev,
@@ -592,9 +593,10 @@ def _cappi_from_session(
     blocks = [session.array(f"{vcp}/sweep_{si}/{moment}")[tsl]
               for si in sweeps]
     stacked = np.stack(blocks, axis=1)                      # (T, S, A, R)
-    out = np.asarray(ops.grid_map(
-        _flat_gates(stacked), mapping.gate_idx, mapping.weights, mode=mode,
-    )).reshape(-1, grid.ny, grid.nx)
+    out = ops.to_host(
+        ops.grid_map, _flat_gates(stacked), mapping.gate_idx,
+        mapping.weights, mode=mode,
+    ).reshape(-1, grid.ny, grid.nx)
     return GridProduct(
         out, np.asarray(times), grid, moment, "cappi",
         {"vcp": vcp, "sweeps": [int(s) for s in sweeps],
@@ -672,9 +674,10 @@ def _column_max_from_session(
         mapping = build_mapping(site_lat, site_lon, az, rng, e, grid,
                                 method=method)
         block = session.array(f"{vcp}/sweep_{si}/{moment}")[tsl]
-        per_sweep.append(np.asarray(ops.grid_map(
-            _flat_gates(block), mapping.gate_idx, mapping.weights, mode=mode,
-        )))
+        per_sweep.append(ops.to_host(
+            ops.grid_map, _flat_gates(block), mapping.gate_idx,
+            mapping.weights, mode=mode,
+        ))
     # fmax: NaN only where *every* sweep is NaN (out of everyone's reach)
     out = np.fmax.reduce(np.stack(per_sweep, axis=0), axis=0)
     return GridProduct(

@@ -257,18 +257,19 @@ class IncrementalGridProduct:
                 m = int(reach.sum())
                 if m:
                     stacked = np.stack(blocks, axis=1)   # (T, S, A, R)
-                    compact = np.asarray(ops.grid_map(
-                        _flat_gates(stacked), mapping.gate_idx[reach],
-                        mapping.weights[reach], mode=req.mode))
+                    compact = ops.to_host(
+                        ops.grid_map, _flat_gates(stacked),
+                        mapping.gate_idx[reach], mapping.weights[reach],
+                        mode=req.mode)
             else:  # column_max
                 maps = [build_mapping(site_lat, site_lon, az, rng, e, grid,
                                       method=method) for e in elevs]
                 reach = np.logical_or.reduce([mp.in_reach() for mp in maps])
                 m = int(reach.sum())
                 if m:
-                    per_sweep = [np.asarray(ops.grid_map(
-                        _flat_gates(block), mp.gate_idx[reach],
-                        mp.weights[reach], mode=req.mode))
+                    per_sweep = [ops.to_host(
+                        ops.grid_map, _flat_gates(block), mp.gate_idx[reach],
+                        mp.weights[reach], mode=req.mode)
                         for mp, block in zip(maps, blocks)]
                     compact = np.fmax.reduce(np.stack(per_sweep, axis=0),
                                              axis=0)
@@ -280,8 +281,8 @@ class IncrementalGridProduct:
             if m:
                 pos = np.full(C, -1, np.int32)
                 pos[np.flatnonzero(reach)] = np.arange(m, dtype=np.int32)
-                rows = np.asarray(ops.grid_update(
-                    canvas, compact, pos, op="set", mode=req.mode))
+                rows = ops.to_host(ops.grid_update, canvas, compact, pos,
+                                   op="set", mode=req.mode)
             else:
                 rows = canvas
             rows = rows.reshape(t_new, grid.ny, grid.nx)
@@ -409,9 +410,9 @@ def _fold_terms(accum: np.ndarray, rates: np.ndarray, dt_s: np.ndarray,
                 continue
             p = np.full(term.size, -1, np.int32)
             p[wet] = np.arange(wet.size, dtype=np.int32)
-            accum = np.asarray(ops.grid_update(
-                accum[None, :], term[wet][None, :], p, op="add",
-                mode=mode)).reshape(-1).astype(np.float32)
+            accum = ops.to_host(
+                ops.grid_update, accum[None, :], term[wet][None, :], p,
+                op="add", mode=mode).reshape(-1).astype(np.float32)
             touched += int(wet.size)
     return accum, touched
 

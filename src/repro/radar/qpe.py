@@ -87,7 +87,7 @@ def _qpe_from_session(
     times = session.array(f"{vcp}/time")[time_slice]
     dbz = session.array(f"{base}/{moment}")[time_slice]
     dt_s = _dt_weights(times)
-    accum = np.asarray(ops.zr_accum(dbz, dt_s, a=a, b=b, mode=mode))
+    accum = ops.to_host(ops.zr_accum, dbz, dt_s, a=a, b=b, mode=mode)
     return QPEResult(
         accum_mm=accum,
         total_hours=float(dt_s.sum() / 3600.0),

@@ -99,9 +99,8 @@ def _qvp_from_session(
     ):
         quality = session.array(f"{base}/{quality_moment}")[time_slice]
 
-    profile = np.asarray(
-        ops.qvp_reduce(field, quality, quality_min=quality_min, mode=mode)
-    )
+    profile = ops.to_host(ops.qvp_reduce, field, quality,
+                          quality_min=quality_min, mode=mode)
     rng_m = session.array(f"{base}/range").read()
     elev = float(session.group_attrs(base)["fixed_angle"])
     height = geometry.beam_height_m(rng_m, elev)

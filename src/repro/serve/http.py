@@ -36,8 +36,10 @@ API's result.  ``benchmarks/bench_serve.py`` gates exactly that.
 
 from __future__ import annotations
 
+import itertools
 import struct
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, HTTPServer
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -45,6 +47,7 @@ from urllib.parse import parse_qs, urlsplit
 
 import numpy as np
 
+from repro import obs
 from repro.analysis.dynamic.runtime import (new_lock, note_read, note_write,
                                             wrap_pool)
 from repro.catalog import query as q
@@ -69,6 +72,9 @@ DEFAULT_PRODUCT_CACHE_BYTES = 32 << 20
 DEFAULT_SESSIONS_PER_TENANT = 8
 
 _MAGIC = b"RPRD"  # payload frame magic: repro product/payload v1
+
+# the ``id`` of each handled request's span, unique within the process
+_REQUEST_SERIAL = itertools.count(1)
 
 
 class ApiError(Exception):
@@ -137,34 +143,36 @@ def encode_product(result: Any) -> bytes:
     excluded: a served body must be bitwise-identical to encoding the
     same in-process computation regardless of what is warm.
     """
-    if isinstance(result, QVPResult):
-        return encode_payload(
-            {"product": "qvp", "moment": result.moment,
-             "elevation_deg": float(result.elevation_deg)},
-            {"profile": result.profile, "times": result.times,
-             "height_m": result.height_m})
-    if isinstance(result, QPEResult):
-        return encode_payload(
-            {"product": "qpe", "total_hours": float(result.total_hours),
-             "n_scans": int(result.n_scans)},
-            {"accum_mm": result.accum_mm, "azimuth": result.azimuth,
-             "range_m": result.range_m})
-    if isinstance(result, GridProduct):
-        return encode_payload(
-            {"product": result.product, "moment": result.moment,
-             "params": result.params, "grid": _grid_doc(result.grid)},
-            {"values": result.values, "times": result.times})
-    if isinstance(result, FederatedMosaic):
-        arrays: Dict[str, np.ndarray] = {"composite": result.composite}
-        for repo_id, prod in result.results.items():
-            arrays[f"{repo_id}/values"] = prod.values
-            arrays[f"{repo_id}/times"] = prod.times
-        return encode_payload(
-            {"product": result.product, "moment": result.moment,
-             "repo_ids": list(result.repo_ids),
-             "grid": _grid_doc(result.grid)},
-            arrays)
-    raise TypeError(f"unencodable product result: {type(result).__name__}")
+    with obs.span("product.encode"):
+        if isinstance(result, QVPResult):
+            return encode_payload(
+                {"product": "qvp", "moment": result.moment,
+                 "elevation_deg": float(result.elevation_deg)},
+                {"profile": result.profile, "times": result.times,
+                 "height_m": result.height_m})
+        if isinstance(result, QPEResult):
+            return encode_payload(
+                {"product": "qpe", "total_hours": float(result.total_hours),
+                 "n_scans": int(result.n_scans)},
+                {"accum_mm": result.accum_mm, "azimuth": result.azimuth,
+                 "range_m": result.range_m})
+        if isinstance(result, GridProduct):
+            return encode_payload(
+                {"product": result.product, "moment": result.moment,
+                 "params": result.params, "grid": _grid_doc(result.grid)},
+                {"values": result.values, "times": result.times})
+        if isinstance(result, FederatedMosaic):
+            arrays: Dict[str, np.ndarray] = {"composite": result.composite}
+            for repo_id, prod in result.results.items():
+                arrays[f"{repo_id}/values"] = prod.values
+                arrays[f"{repo_id}/times"] = prod.times
+            return encode_payload(
+                {"product": result.product, "moment": result.moment,
+                 "repo_ids": list(result.repo_ids),
+                 "grid": _grid_doc(result.grid)},
+                arrays)
+        raise TypeError(
+            f"unencodable product result: {type(result).__name__}")
 
 
 # ---------------------------------------------------------------------------
@@ -554,20 +562,21 @@ class ArchiveService:
         :func:`repro.radar.products.compute_product`: mosaics against
         the catalog, the single-archive kinds against the tenant's
         cached session."""
-        req = self._request_for(kind, clean)
-        if kind == "mosaic":
-            return compute_product(self.catalog, req,
-                                   read_workers=self._read_workers)
-        session = self.session(tenant, clean["repo"])
-        try:
-            return compute_product(session, req)
-        except KeyError as exc:
-            # the store's NotFound is a KeyError: a missing VCP, sweep or
-            # moment.  Anything else (a kernel or compile failure) is the
-            # server's fault and goes out as a 500.
-            raise ApiError(
-                404, f"product inputs not found: "
-                     f"{type(exc).__name__}: {exc}") from None
+        with obs.span("product.compute", kind=kind):
+            req = self._request_for(kind, clean)
+            if kind == "mosaic":
+                return compute_product(self.catalog, req,
+                                       read_workers=self._read_workers)
+            session = self.session(tenant, clean["repo"])
+            try:
+                return compute_product(session, req)
+            except KeyError as exc:
+                # the store's NotFound is a KeyError: a missing VCP, sweep or
+                # moment.  Anything else (a kernel or compile failure) is the
+                # server's fault and goes out as a 500.
+                raise ApiError(
+                    404, f"product inputs not found: "
+                         f"{type(exc).__name__}: {exc}") from None
 
     # -- watch -----------------------------------------------------------
     def watch(self, params: Dict[str, List[str]]) -> Dict[str, Any]:
@@ -606,6 +615,8 @@ class ArchiveService:
 
     # -- stats / shutdown ------------------------------------------------
     def stats(self) -> Dict[str, Any]:
+        """Flight, cache and per-tenant session counters, and the
+        process's per-layer time (``spans``: :func:`repro.obs.snapshot`)."""
         with self._lock:
             note_read(self, "_tenant_sessions", owner="ArchiveService")
             tenants = dict(self._tenant_sessions)
@@ -616,6 +627,7 @@ class ArchiveService:
             "chunk_cache": self._chunk_cache.stats(),
             "session_flight": self._session_flight.stats(),
             "tenants": {t: c.stats() for t, c in sorted(tenants.items())},
+            "spans": obs.snapshot(),
         }
 
     def close(self) -> None:
@@ -721,6 +733,7 @@ def create_app(service: ArchiveService):
             parts = [p for p in url.path.split("/") if p]
             params = parse_qs(url.query, keep_blank_values=True)
             tenant = self._tenant()
+            obs.annotate(route=url.path, tenant=tenant)
 
             if parts == ["catalog"]:
                 body = json_dumps(service.catalog_doc())
@@ -764,7 +777,11 @@ def create_app(service: ArchiveService):
 class _PooledHTTPServer(HTTPServer):
     """An ``HTTPServer`` dispatching each connection onto a bounded,
     sanitizer-wrapped worker pool (``ThreadingMixIn`` without the
-    unbounded thread-per-request)."""
+    unbounded thread-per-request).
+
+    Each connection's wait for a worker is recorded as ``http.queue``;
+    its handling is the span ``http.request``, carrying a per-process
+    serial ``id`` (and, once parsed, its ``route`` and ``tenant``)."""
 
     daemon_threads = True
 
@@ -773,11 +790,14 @@ class _PooledHTTPServer(HTTPServer):
         self._pool = pool
 
     def process_request(self, request, client_address) -> None:
-        self._pool.submit(self._handle, request, client_address)
+        self._pool.submit(self._handle, request, client_address,
+                          time.perf_counter())
 
-    def _handle(self, request, client_address) -> None:
+    def _handle(self, request, client_address, submitted: float) -> None:
+        obs.record("http.queue", time.perf_counter() - submitted)
         try:
-            self.finish_request(request, client_address)
+            with obs.span("http.request", id=next(_REQUEST_SERIAL)):
+                self.finish_request(request, client_address)
         except Exception:
             self.handle_error(request, client_address)
         finally:

@@ -59,6 +59,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.analysis.dynamic.runtime import (new_lock, note_read, note_write,
                                             wrap_pool)
 
@@ -702,7 +703,10 @@ class Session:
 
     def get_blob(self, ref: str) -> bytes:
         """Raw chunk payload for one content hash (single GET)."""
-        return self.repo.store.get(f"chunks/{ref}")
+        with obs.span("store.get") as sp:
+            blob = self.repo.store.get(f"chunks/{ref}")
+            sp.nbytes = len(blob)
+        return blob
 
     def get_blobs(self, refs: Sequence[str]) -> Dict[str, bytes]:
         """Raw chunk payloads for several content hashes in **one**
@@ -715,10 +719,12 @@ class Session:
         uniq = list(dict.fromkeys(refs))
         keys = [f"chunks/{r}" for r in uniq]
         get_many = getattr(self.repo.store, "get_many", None)
-        if get_many is None:
-            got = {k: self.repo.store.get(k) for k in keys}
-        else:
-            got = get_many(keys)
+        with obs.span("store.get") as sp:
+            if get_many is None:
+                got = {k: self.repo.store.get(k) for k in keys}
+            else:
+                got = get_many(keys)
+            sp.nbytes = sum(len(b) for b in got.values())
         return {r: got[f"chunks/{r}"] for r in uniq}
 
     def _prefetch_manifests(self, array_paths: Sequence[str], *,
@@ -886,8 +892,10 @@ class Session:
         try:
             blobs = self.get_blobs([k[0] for k in keys])
             for key in keys:
-                chunk = decode_chunk(blobs[key[0]], key[1], key[2], key[3],
-                                     writable=False)
+                with obs.span("store.decode") as sp:
+                    chunk = decode_chunk(blobs[key[0]], key[1], key[2],
+                                         key[3], writable=False)
+                    sp.nbytes = chunk.nbytes
                 self._admit_prefetched(key, chunk)
         finally:
             with self._cache_lock:
@@ -963,8 +971,10 @@ class Session:
             # batch failed, timed out, or admission dropped the chunk:
             # fall through to a direct (possibly duplicate) fetch
         blob = self.get_blob(ref)
-        chunk = decode_chunk(blob, tuple(meta.chunks), meta.dtype,
-                             meta.codec, writable=False)
+        with obs.span("store.decode") as sp:
+            chunk = decode_chunk(blob, tuple(meta.chunks), meta.dtype,
+                                 meta.codec, writable=False)
+            sp.nbytes = chunk.nbytes
         with self._cache_lock:
             note_write(self, "_fetch_count", owner="Session")
             self._fetch_count += 1

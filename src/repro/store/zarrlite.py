@@ -17,6 +17,8 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .chunks import (ChunkGrid, normalize_selection, predicate_mask,
                      selection_bounds)
 from .codecs import default_codec
@@ -168,7 +170,11 @@ class Array:
             sels.append(slice(None))
         bounds = [sl.indices(dim) for sl, dim in zip(sels, self.meta.shape)]
         out_shape = tuple(max(0, b[1] - b[0]) for b in bounds)
-        out = np.full(out_shape, self.meta.fill_value, dtype=self.dtype)
+        # the output buffer and each chunk's copy into it: the span
+        # ``store.assemble``, whose bytes are the output's
+        with obs.span("store.assemble") as sp:
+            out = np.full(out_shape, self.meta.fill_value, dtype=self.dtype)
+            sp.nbytes = out.nbytes
         grid = self.meta.grid
 
         def fill_from(cid) -> None:
@@ -181,7 +187,8 @@ class Array:
                 hi = min(cs.stop, b[1])
                 src.append(slice(lo - cs.start, hi - cs.start))
                 dst.append(slice(lo - b[0], hi - b[0]))
-            out[tuple(dst)] = chunk[tuple(src)]
+            with obs.span("store.assemble"):
+                out[tuple(dst)] = chunk[tuple(src)]
 
         cids = list(grid.chunks_for_selection(sels))
         pool = self._session.reader_pool() if len(cids) > 1 else None
