@@ -35,7 +35,8 @@ import os
 import sys
 import threading
 from concurrent.futures import Future
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from contextlib import contextmanager
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
 
 from .detector import RaceDetector
 
@@ -353,6 +354,20 @@ def wrap_pool(pool):
     return TracedPool(pool)
 
 
+@contextmanager
+def external_wait(desc: str) -> Iterator[None]:
+    """Mark a wait for another thread that the explorer cannot arbitrate.
+
+    While it waits (for an event a pool task sets), a managed thread
+    hands control away.  A no-op when no explorer runs."""
+    sch = rt.scheduler if rt.enabled else None
+    if sch is None:
+        yield
+        return
+    with sch.external(desc):
+        yield
+
+
 # -- access notes -----------------------------------------------------------
 
 def _obj_loc(obj, attr: str) -> str:
@@ -439,6 +454,6 @@ if os.environ.get("REPRO_TSAN") == "1":
 
 __all__ = [
     "Runtime", "TracedFuture", "TracedLock", "TracedPool", "TracedRLock",
-    "atomic_read", "atomic_update", "new_lock", "new_rlock", "note_read",
-    "note_write", "rt", "schedule_point", "wrap_pool",
+    "atomic_read", "atomic_update", "external_wait", "new_lock", "new_rlock",
+    "note_read", "note_write", "rt", "schedule_point", "wrap_pool",
 ]

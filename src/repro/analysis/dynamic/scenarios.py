@@ -19,7 +19,10 @@ concurrent entry points the ROADMAP's service ambitions lean on:
   merge through the catalog document's read-modify-CAS loop,
 * ``feed-vs-compaction`` — a scan-per-commit :class:`repro.etl.LiveFeed`
   races background compaction on the same repository; the compactor
-  rebases over the appends and no scan is lost or torn.
+  rebases over the appends and no scan is lost or torn,
+* ``pooled-reads`` — two multi-chunk reads on one session share its
+  reader pool (:mod:`repro.store.readpool`): each caller takes chunks
+  beside its helpers and waits only for helpers holding one.
 
 ``scripts/lint.py --dynamic`` sweeps this corpus with
 :func:`repro.analysis.dynamic.scheduler.verify_clean`; regression tests
@@ -281,6 +284,31 @@ def feed_vs_compaction() -> Scenario:
                     check=check, teardown=_teardown)
 
 
+def pooled_reads() -> Scenario:
+    """Two readers of one session spread their chunks over its pool."""
+
+    def setup():
+        root = _mkdtemp()
+        repo = _new_repo(root)
+        tx = repo.writable_session()
+        tx.create_array("x", shape=(8,), dtype="int32",
+                        chunks=(2,)).write_full(np.arange(8, dtype="int32"))
+        tx.commit("seed")
+        return {"root": root,
+                "session": repo.readonly_session(read_workers=2)}
+
+    def reader(ctx) -> None:
+        np.testing.assert_array_equal(ctx["session"].array("x").read(),
+                                      np.arange(8, dtype="int32"))
+
+    def final_close(ctx) -> None:
+        ctx["session"].close()
+        _teardown(ctx)
+
+    return Scenario("pooled-reads", setup,
+                    [("r0", reader), ("r1", reader)], teardown=final_close)
+
+
 CORPUS: Dict[str, Callable[[], Scenario]] = {
     "commit-vs-commit-rebase": commit_vs_commit_rebase,
     "gc-vs-inflight-commit": gc_vs_inflight_commit,
@@ -288,6 +316,7 @@ CORPUS: Dict[str, Callable[[], Scenario]] = {
     "close-vs-first-read": close_vs_first_read,
     "catalog-register-cas-retry": catalog_register_cas_retry,
     "feed-vs-compaction": feed_vs_compaction,
+    "pooled-reads": pooled_reads,
 }
 
 

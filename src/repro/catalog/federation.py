@@ -89,9 +89,10 @@ def _one_target_per_repo(plan_: QueryPlan) -> "OrderedDict[str, Target]":
 
 def _fan_out(catalog, payloads: "OrderedDict[str, object]",
              fn: Callable, *, workers: Optional[int], read_workers: int,
-             entries=None) -> "OrderedDict[str, object]":
+             entries=None, read_pool=None) -> "OrderedDict[str, object]":
     """Run ``fn(session, payload)`` per repository over a thread pool,
-    preserving the mapping's (sorted-repo) order in the result."""
+    preserving the mapping's (sorted-repo) order in the result.  A
+    ``read_pool`` is lent to every session (``Session.read_pool``)."""
     if entries is None:  # one catalog-document fetch, not per repo
         entries = catalog.entries()
 
@@ -99,6 +100,8 @@ def _fan_out(catalog, payloads: "OrderedDict[str, object]",
         repo_id, payload = item
         session = catalog.open_session(repo_id, entry=entries.get(repo_id),
                                        read_workers=read_workers)
+        if read_pool is not None:
+            session.read_pool = read_pool
         try:
             return fn(session, payload)
         finally:
@@ -205,6 +208,7 @@ def federated_qvp(
     mode: str = "auto",
     workers: Optional[int] = None,
     read_workers: int = 1,
+    read_pool=None,
 ) -> FederatedQVP:
     """QVP across every catalogued repository the predicates match."""
     plan_ = plan(catalog,
@@ -222,7 +226,8 @@ def federated_qvp(
         ))
 
     results = _fan_out(catalog, targets, run, workers=workers,
-                       read_workers=read_workers, entries=plan_.entries)
+                       read_workers=read_workers, entries=plan_.entries,
+                       read_pool=read_pool)
     heights = [r.height_m for r in results.values()]
     if any(h.shape != heights[0].shape
            or not np.allclose(h, heights[0], rtol=1e-6, atol=1.0)
@@ -257,6 +262,7 @@ def federated_qpe(
     mode: str = "auto",
     workers: Optional[int] = None,
     read_workers: int = 1,
+    read_pool=None,
 ) -> FederatedQPE:
     """Z–R accumulation per site across the federation."""
     plan_ = plan(catalog,
@@ -273,7 +279,8 @@ def federated_qpe(
         ))
 
     results = _fan_out(catalog, targets, run, workers=workers,
-                       read_workers=read_workers, entries=plan_.entries)
+                       read_workers=read_workers, entries=plan_.entries,
+                       read_pool=read_pool)
     return FederatedQPE(repo_ids=list(results), results=results)
 
 
@@ -362,6 +369,7 @@ def _federated_mosaic(
     mode: str = "auto",
     workers: Optional[int] = None,
     read_workers: int = 1,
+    read_pool=None,
 ) -> FederatedMosaic:
     # the mosaic implementation (dispatched via repro.radar.products).
     # The planner does the pruning: repositories outside ``within`` (a
@@ -442,7 +450,8 @@ def _federated_mosaic(
         return prod
 
     results = _fan_out(catalog, by_repo, run, workers=workers,
-                       read_workers=read_workers, entries=plan_.entries)
+                       read_workers=read_workers, entries=plan_.entries,
+                       read_pool=read_pool)
     composite = np.fmax.reduce(
         np.stack([r.composite() for r in results.values()], axis=0), axis=0
     )

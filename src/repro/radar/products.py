@@ -132,13 +132,15 @@ def _compute_session(session, req: ProductRequest):
     )
 
 
-def _compute_catalog(catalog, req: ProductRequest, *, workers, read_workers):
+def _compute_catalog(catalog, req: ProductRequest, *, workers, read_workers,
+                     read_pool):
     # late import: federation imports this module for its own routing
     from ..catalog import federation as fed
 
     common = dict(moment=req.moment, vcp=req.vcp,
                   time_between=req.time_between, repos=req.repos,
-                  mode=req.mode, workers=workers, read_workers=read_workers)
+                  mode=req.mode, workers=workers, read_workers=read_workers,
+                  read_pool=read_pool)
     if req.kind == "mosaic":
         return fed._federated_mosaic(
             catalog, product=req.product, altitude_m=req.altitude_m,
@@ -165,13 +167,15 @@ def _compute_catalog(catalog, req: ProductRequest, *, workers, read_workers):
 
 
 def compute_product(target, request: ProductRequest, *,
-                    workers: Optional[int] = None, read_workers: int = 1):
+                    workers: Optional[int] = None, read_workers: int = 1,
+                    read_pool=None):
     """Compute ``request`` against ``target`` and return its result.
 
     ``target`` is either a read :class:`~repro.store.Session` (one
     archive; returns ``QVPResult`` / ``QPEResult`` / ``GridProduct``) or
     a :class:`~repro.catalog.Catalog` (the whole federation; returns the
-    ``Federated*`` result types).  ``workers`` / ``read_workers`` are
+    ``Federated*`` result types).  ``workers`` / ``read_workers`` /
+    ``read_pool`` (an executor lent to every session opened) are
     execution knobs for catalog targets and are deliberately *not* part
     of the request: the same request replays identically on any
     executor.
@@ -182,7 +186,8 @@ def compute_product(target, request: ProductRequest, *,
         )
     if _is_catalog(target):
         return _compute_catalog(target, request, workers=workers,
-                                read_workers=read_workers)
+                                read_workers=read_workers,
+                                read_pool=read_pool)
     return _compute_session(target, request)
 
 

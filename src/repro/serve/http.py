@@ -57,6 +57,7 @@ from repro.radar.products import (PRODUCT_KINDS, compute_product,
                                   request_from_params)
 from repro.radar.qpe import QPEResult
 from repro.radar.qvp import QVPResult
+from repro.store import readpool
 from repro.store.chunks import ChunkGrid, content_hash
 from repro.store.codecs import json_dumps, json_loads
 
@@ -224,16 +225,22 @@ class ArchiveService:
     cached per tenant with an LRU slot budget.  ``sessions_per_tenant``
     must be at least the number of repositories a tenant touches
     concurrently — an evicted session closes, so a smaller budget only
-    costs reopen latency, never correctness of *new* requests.
+    costs reopen latency, never correctness of *new* requests.  Unless
+    ``read_workers`` is given, every session it opens reads on the
+    process's shared read pool (:func:`repro.store.readpool.shared_pool`).
     """
 
     def __init__(self, catalog, *,
                  chunk_cache_bytes: int = DEFAULT_CHUNK_CACHE_BYTES,
                  product_cache_bytes: int = DEFAULT_PRODUCT_CACHE_BYTES,
                  sessions_per_tenant: int = DEFAULT_SESSIONS_PER_TENANT,
-                 read_workers: int = 1) -> None:
+                 read_workers: Optional[int] = None) -> None:
         self.catalog = catalog
-        self._read_workers = int(read_workers)
+        # sessions read on the process's shared pool unless the caller
+        # sized their own pools with ``read_workers``
+        self._read_workers = int(read_workers or 1)
+        self._read_pool = (readpool.shared_pool() if read_workers is None
+                           else None)
         self._sessions_per_tenant = int(sessions_per_tenant)
         self._chunk_cache = ByteBudgetCache(chunk_cache_bytes)
         self._product_cache = ByteBudgetCache(product_cache_bytes)
@@ -270,6 +277,7 @@ class ArchiveService:
             except KeyError:
                 raise ApiError(
                     404, f"unknown repository {repo_id!r}") from None
+            s.read_pool = self._read_pool
             for _key, old in cache.put(repo_id, s, 1):
                 old.close()
             return s
@@ -566,7 +574,8 @@ class ArchiveService:
             req = self._request_for(kind, clean)
             if kind == "mosaic":
                 return compute_product(self.catalog, req,
-                                       read_workers=self._read_workers)
+                                       read_workers=self._read_workers,
+                                       read_pool=self._read_pool)
             session = self.session(tenant, clean["repo"])
             try:
                 return compute_product(session, req)

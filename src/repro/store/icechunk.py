@@ -25,8 +25,9 @@ run against any :class:`~repro.store.object_store.ObjectStore`:
   touches it, mirroring the v1→v2 manifest migration.
 * **Cached, concurrent reads** — every session carries an LRU decoded-
   chunk cache plus a manifest-shard cache, and multi-chunk selections can
-  fan out over a thread pool (object-store ``get`` and codec decode both
-  release the GIL), so QVP/time-series workloads issue parallel reads.
+  spread their chunks over a thread pool (object-store ``get`` and codec
+  decode both release the GIL; :mod:`repro.store.readpool`), so
+  QVP/time-series workloads decode in parallel.
 * **Snapshots** — a snapshot document references group/array metadata and
   manifest hashes, plus its parent snapshot.  Snapshot ids are content
   hashes of the canonical document: the same data produces the same id,
@@ -54,15 +55,17 @@ import os
 import threading
 import time
 from collections import OrderedDict
+from functools import partial
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro import obs
-from repro.analysis.dynamic.runtime import (new_lock, note_read, note_write,
-                                            wrap_pool)
+from repro.analysis.dynamic.runtime import (external_wait, new_lock,
+                                            note_read, note_write, wrap_pool)
 
+from . import readpool
 from .chunks import (chunk_stats_summary, content_hash, decode_chunk,
                      encode_chunk, normalize_selection)
 from .codecs import get_codec, json_dumps, json_loads
@@ -123,10 +126,31 @@ _OBJ_CACHE_ENTRIES = 1024
 # batches of at most this many keys, so one slow giant batch never
 # serializes the whole prefetch plan behind a single round trip
 PREFETCH_BATCH_KEYS = 16
-# how long a demand read waits for an in-flight prefetch of the same chunk
-# before falling back to a direct fetch (a safety net, not a code path the
-# healthy pipeline ever takes)
+# how long a demand read waits for a GET or a decode of the same chunk
+# that a prefetch plan has running before falling back to a direct fetch
+# (a safety net, not a code path the healthy pipeline ever takes)
 _INFLIGHT_WAIT_S = 15.0
+
+# where a prefetch plan's chunk is; a state only moves forward:
+# queued (its batch has not started) -> fetching (the batch's GET runs)
+# -> fetched (the payload is here, nobody decodes it) -> decoding
+_QUEUED, _FETCHING, _FETCHED, _DECODING = range(4)
+
+
+class _Pending:
+    """A chunk a prefetch plan holds until it is in the cache or let go.
+
+    ``fetched`` is set when it leaves the fetching state, ``done`` when
+    the hold is released, so a waiter only ever waits for work that is
+    running.  Its fields change under the session's cache lock."""
+
+    __slots__ = ("state", "blob", "fetched", "done")
+
+    def __init__(self) -> None:
+        self.state = _QUEUED
+        self.blob: Optional[bytes] = None
+        self.fetched = threading.Event()
+        self.done = threading.Event()
 
 
 @dataclass
@@ -158,7 +182,8 @@ class PrefetchReport:
         """
         jobs, self._jobs = self._jobs, []
         for job in jobs:
-            job.result()
+            for decode in job.result():
+                decode.result()
         return self
 
 
@@ -480,11 +505,13 @@ class Session:
 
     Carries two LRU caches shared by all arrays it opens — decoded chunks
     (budgeted in bytes) and manifest shards (budgeted in entries) — plus an
-    optional reader thread pool (``read_workers``) that
-    :meth:`~repro.store.zarrlite.Array.__getitem__` fans multi-chunk
-    selections out over.  Cached chunks are read-only and keyed by content
-    hash, so they are immutable by construction; writers always mutate
-    private copies.
+    optional reader thread pool that
+    :meth:`~repro.store.zarrlite.Array.__getitem__` spreads multi-chunk
+    selections over (:meth:`read_chunks`): its own, of ``read_workers``
+    threads, or one lent as ``read_pool`` (the archive service lends the
+    process's shared pool).  Cached chunks are read-only and keyed by
+    content hash, so they are immutable by construction; writers always
+    mutate private copies.
     """
 
     def __init__(self, repo: Repository, snapshot_id: str, *, writable: bool,
@@ -513,10 +540,10 @@ class Session:
         # chunk payloads actually fetched+decoded (cache misses) — the
         # "chunks read" accounting fragmentation benchmarks compare
         self._fetch_count = 0
-        # cache keys a prefetch batch is currently fetching; the Event is
-        # set when the batch lands so demand readers can wait instead of
+        # cache keys a prefetch plan holds until they land (_Pending), so
+        # demand readers take the chunk over or wait for it instead of
         # issuing a duplicate GET
-        self._inflight: Dict[Tuple, threading.Event] = {}
+        self._inflight: Dict[Tuple, _Pending] = {}
         # prefetched-but-not-yet-read cache keys: shielded from demand
         # eviction until first use, so a large demand burst cannot flush
         # the plan it is about to consume
@@ -798,14 +825,38 @@ class Session:
         chunks whose estimated decoded size would overflow ``cache_bytes``
         are *deferred* to demand paging rather than fetched and dropped.
         Writable sessions skip prefetching entirely (staged chunks shadow
-        committed ones).  With ``wait=False`` the returned report's
-        batches run on the reader pool in the background; call
-        :meth:`PrefetchReport.wait` (or just start reading — demand reads
-        wait on in-flight chunks) to synchronize.
+        committed ones).  Without a reader pool the batches run here, in
+        order.  With one, a batch's GET is one pool task and each chunk's
+        decode another: ``wait=True`` drains them here and on the pool
+        (:func:`~repro.store.readpool.drain`); with ``wait=False`` they
+        run on the pool in the background — call
+        :meth:`PrefetchReport.wait` (or just start reading — a demand read
+        decodes a fetched chunk itself, fetches one whose batch has not
+        started, and waits only for a GET or decode already running).
         """
+        report, batches = self._plan(items)
+        if not batches:
+            return report
+        pool = self.reader_pool()
+        if pool is None or wait:
+            self._run(pool, batches, [partial(self._finish, key)
+                                      for batch in batches for key in batch])
+            return report
+        try:
+            for batch in batches:
+                report._jobs.append(pool.submit(self._spread, batch, pool))
+        except BaseException:  # the pool was shut down
+            self._release([k for b in batches for k in b], idle=True)
+            raise
+        return report
+
+    def _plan(self, items) -> Tuple[PrefetchReport, List[List[Tuple]]]:
+        """Resolve a prefetch plan, admit it against the cache budget and
+        hold each scheduled chunk (:class:`_Pending`); the report and the
+        GET batches."""
         report = PrefetchReport()
         if self.writable:
-            return report
+            return report, []
         norm: List[Tuple[str, Any]] = []
         for item in items:
             if isinstance(item, str):
@@ -814,7 +865,7 @@ class Session:
                 path, sel = item
                 norm.append((path, sel))
         if not norm:
-            return report
+            return report, []
         self._prefetch_manifests([p for p, _ in norm])
         # resolve the plan: unique cache keys, grouped by manifest shard
         plan: "OrderedDict[Tuple, Tuple[str, int]]" = OrderedDict()
@@ -844,10 +895,10 @@ class Session:
                 est_bytes[key] = est
         report.planned = len(plan)
         if not plan:
-            return report
-        # admission + in-flight marking happen atomically, *before* any
-        # batch is submitted: a demand read racing the plan either sees
-        # the cached chunk or an in-flight marker it can wait on
+            return report, []
+        # admission + holds happen atomically, *before* any batch runs: a
+        # demand read racing the plan either sees the cached chunk or a
+        # hold it can take over or wait on
         groups: "OrderedDict[Tuple[str, int], List[Tuple]]" = OrderedDict()
         with self._cache_lock:
             note_read(self, "_chunk_cache", owner="Session")
@@ -866,7 +917,7 @@ class Session:
                     continue
                 projected += est_bytes[key]
                 note_write(self, "_inflight", owner="Session")
-                self._inflight[key] = threading.Event()
+                self._inflight[key] = _Pending()
                 groups.setdefault(group, []).append(key)
                 report.scheduled += 1
         batches: List[List[Tuple]] = []
@@ -874,36 +925,130 @@ class Session:
             for i in range(0, len(keys), PREFETCH_BATCH_KEYS):
                 batches.append(keys[i:i + PREFETCH_BATCH_KEYS])
         report.batches = len(batches)
-        pool = self.reader_pool()
-        if pool is None:
-            for batch in batches:
-                self._fetch_group(batch)
-        else:
-            for batch in batches:
-                report._jobs.append(pool.submit(self._fetch_group, batch))
-            if wait:
-                report.wait()
-        return report
+        return report, batches
 
-    def _fetch_group(self, keys: Sequence[Tuple]) -> None:
-        """Fetch one coalesced batch: a single ``get_many`` round trip,
-        decode, admit each chunk, then release the in-flight markers
-        (always — waiters must never hang on a failed batch)."""
-        try:
-            blobs = self.get_blobs([k[0] for k in keys])
+    def _fetch_batch(self, keys: Sequence[Tuple]) -> List[Tuple]:
+        """One coalesced GET (``get_many``) for the chunks of a batch that
+        no reader has taken over; leaves them fetched and returns them.
+        A failed GET releases its holds (waiters never hang on it)."""
+        mine = []
+        with self._cache_lock:
+            note_read(self, "_inflight", owner="Session")
             for key in keys:
-                with obs.span("store.decode") as sp:
-                    chunk = decode_chunk(blobs[key[0]], key[1], key[2],
-                                         key[3], writable=False)
-                    sp.nbytes = chunk.nbytes
-                self._admit_prefetched(key, chunk)
-        finally:
+                pending = self._inflight.get(key)
+                if pending is not None and pending.state == _QUEUED:
+                    pending.state = _FETCHING
+                    mine.append(key)
+        if not mine:
+            return mine
+        try:
+            blobs = self.get_blobs([k[0] for k in mine])
+        except BaseException:
+            self._release(mine)
+            raise
+        with self._cache_lock:
+            note_read(self, "_inflight", owner="Session")
+            for key in mine:
+                pending = self._inflight[key]
+                pending.blob = blobs[key[0]]
+                pending.state = _FETCHED
+                pending.fetched.set()
+        return mine
+
+    def _finish(self, key: Tuple, wait: bool = True) -> Optional[Any]:
+        """Settle a prefetch plan's hold on ``key``.
+
+        A fetched payload is decoded here and admitted; with ``wait`` a
+        chunk whose batch has not started is fetched here too, and a GET
+        or decode already running is waited for.  Returns the chunk when
+        this call decoded it (admission may have left it out of the
+        cache), else None.
+        """
+        while True:
             with self._cache_lock:
-                note_write(self, "_inflight", owner="Session")
-                for key in keys:
-                    ev = self._inflight.pop(key, None)
-                    if ev is not None:
-                        ev.set()
+                note_read(self, "_inflight", owner="Session")
+                pending = self._inflight.get(key)
+                if pending is None:
+                    return None
+                state = pending.state
+                if state == _FETCHED or (wait and state == _QUEUED):
+                    pending.state = _DECODING
+                    blob, pending.blob = pending.blob, None
+                    break
+            if not wait:
+                return None
+            event = pending.fetched if state == _FETCHING else pending.done
+            with external_wait("Session.prefetch"):
+                if not event.wait(_INFLIGHT_WAIT_S):
+                    return None
+        try:
+            if blob is None:
+                blob = self.get_blob(key[0])
+            with obs.span("store.decode") as sp:
+                chunk = decode_chunk(blob, key[1], key[2], key[3],
+                                     writable=False)
+                sp.nbytes = chunk.nbytes
+            self._admit_prefetched(key, chunk)
+            return chunk
+        finally:
+            self._release([key])
+
+    def _release(self, keys: Sequence[Tuple], *, idle: bool = False) -> None:
+        """Let go of the holds on ``keys`` and wake their waiters; with
+        ``idle``, only those nobody is fetching or decoding."""
+        with self._cache_lock:
+            note_write(self, "_inflight", owner="Session")
+            for key in keys:
+                pending = self._inflight.get(key)
+                if pending is None or (
+                        idle and pending.state in (_FETCHING, _DECODING)):
+                    continue
+                del self._inflight[key]
+                pending.blob = None
+                pending.fetched.set()
+                pending.done.set()
+
+    def _spread(self, keys: Sequence[Tuple], pool) -> List[Any]:
+        """A background batch: its GET here, then each chunk's decode as
+        a task of its own on ``pool``; the decodes' futures."""
+        fetched = self._fetch_batch(keys)
+        try:
+            return [pool.submit(self._finish, key, False) for key in fetched]
+        except BaseException:
+            self._release(fetched, idle=True)
+            raise
+
+    def _run(self, pool, batches: Sequence[List[Tuple]], tasks) -> None:
+        """A plan's GET batches, then ``tasks``: in order here without a
+        pool, else drained here and by helpers on ``pool``
+        (:func:`~repro.store.readpool.drain`).  On a failure, the plan's
+        holds that no task will settle now are let go."""
+        tasks = [partial(self._fetch_batch, b) for b in batches] + tasks
+        try:
+            if pool is None:
+                for task in tasks:
+                    task()
+            else:
+                readpool.drain(pool, tasks)
+        except BaseException:
+            self._release([k for b in batches for k in b], idle=True)
+            raise
+
+    def read_chunks(self, array_path: str, cids, fill) -> None:
+        """Call ``fill(cid)`` once for each chunk of one multi-chunk read
+        (what :meth:`~repro.store.zarrlite.Array.__getitem__` does with a
+        selection that covers more than one chunk).
+
+        The chunks are planned as one prefetch (the same admission, the
+        same batched GETs); the plan's GETs and then the fills are one
+        work list (:meth:`_run`): in order here without a reader pool,
+        else shared with helpers on the pool.  A fill decodes its own
+        chunk once the GET has landed, so ``fill`` must be safe to run
+        concurrently for distinct chunks.
+        """
+        _report, batches = self._plan([(array_path, cids)])
+        self._run(self.reader_pool(), batches,
+                  [partial(fill, cid) for cid in cids])
 
     def _admit_prefetched(self, key: Tuple, chunk) -> None:
         """Byte-budget admission for a prefetched chunk: insert and mark
@@ -948,10 +1093,12 @@ class Session:
         Returns None when the chunk was never written (caller substitutes
         fill value).  The cache key is the chunk's content hash plus its
         decode parameters, so identical payloads shared by several arrays
-        decode once.  A miss on a chunk an active prefetch batch is
-        already fetching waits for that batch instead of issuing a
-        duplicate GET (with a timed fallback to a direct fetch, so a
-        failed batch degrades to the old per-chunk path).
+        decode once.  A miss on a chunk a prefetch plan holds settles the
+        hold instead of issuing a duplicate GET (:meth:`_finish`: decode
+        a fetched payload, fetch a chunk whose batch has not started,
+        wait only for a GET or decode already running — with a timed
+        fallback to a direct fetch, so a failed batch degrades to the old
+        per-chunk path).
         """
         ref = self.chunk_ref(array_path, cid)
         if ref is None:
@@ -960,16 +1107,15 @@ class Session:
         hit = self._cache_lookup(key)
         if hit is not None:
             return hit
-        with self._cache_lock:
-            note_read(self, "_inflight", owner="Session")
-            ev = self._inflight.get(key)
-        if ev is not None:
-            ev.wait(_INFLIGHT_WAIT_S)
-            hit = self._cache_lookup(key)
-            if hit is not None:
-                return hit
-            # batch failed, timed out, or admission dropped the chunk:
-            # fall through to a direct (possibly duplicate) fetch
+        chunk = self._finish(key)
+        if chunk is not None:
+            self._cache_lookup(key)  # the demand hit on a prefetched chunk
+            return chunk
+        hit = self._cache_lookup(key)
+        if hit is not None:
+            return hit
+        # no hold, a failed or timed-out batch, or admission dropped the
+        # chunk: a direct (possibly duplicate) fetch
         blob = self.get_blob(ref)
         with obs.span("store.decode") as sp:
             chunk = decode_chunk(blob, tuple(meta.chunks), meta.dtype,

@@ -12,6 +12,8 @@ This module is deliberately storage-format-first: the Radar DataTree layer
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -170,6 +172,25 @@ class Array:
             sels.append(slice(None))
         bounds = [sl.indices(dim) for sl, dim in zip(sels, self.meta.shape)]
         out_shape = tuple(max(0, b[1] - b[0]) for b in bounds)
+        cids = list(self.meta.grid.chunks_for_selection(sels))
+        if len(cids) > 1:
+            # the span ``store.read``: a multi-chunk read's wall time on
+            # this thread, whose bytes are the output's
+            with obs.span("store.read") as sp:
+                out = self._assemble(cids, bounds, out_shape)
+                sp.nbytes = out.nbytes
+        else:
+            out = self._assemble(cids, bounds, out_shape)
+        if squeeze_axes:
+            out = np.squeeze(out, axis=tuple(squeeze_axes))
+        return out
+
+    def _assemble(self, cids, bounds, out_shape) -> np.ndarray:
+        """The output of a read: the fill value, then each chunk copied
+        into its region.  Several chunks go through
+        :meth:`Session.read_chunks`, which may copy some on helper
+        threads; each copy is counted as ``store.read.pooled`` there and
+        ``store.read.inline`` on this thread."""
         # the output buffer and each chunk's copy into it: the span
         # ``store.assemble``, whose bytes are the output's
         with obs.span("store.assemble") as sp:
@@ -177,7 +198,7 @@ class Array:
             sp.nbytes = out.nbytes
         grid = self.meta.grid
 
-        def fill_from(cid) -> None:
+        def copy(cid) -> None:
             cslices = grid.chunk_slices(cid)
             chunk = self._read_chunk(cid)
             # intersection of chunk extent and request, in both frames
@@ -187,27 +208,24 @@ class Array:
                 hi = min(cs.stop, b[1])
                 src.append(slice(lo - cs.start, hi - cs.start))
                 dst.append(slice(lo - b[0], hi - b[0]))
+            # destination regions are disjoint per chunk, so copies on
+            # several threads never overlap
             with obs.span("store.assemble"):
                 out[tuple(dst)] = chunk[tuple(src)]
 
-        cids = list(grid.chunks_for_selection(sels))
-        pool = self._session.reader_pool() if len(cids) > 1 else None
-        if len(cids) > 1:
-            # coalesce the multi-chunk read into batched GETs up front —
-            # with a pool the batches overlap the fills below (which wait
-            # on in-flight chunks instead of re-fetching); without one the
-            # fills run against a warm cache.  Writable sessions no-op
-            # (staged chunks shadow committed ones).
-            self._session.prefetch([(self.path, cids)], wait=pool is None)
-        if pool is None:
+        if len(cids) <= 1:
             for cid in cids:
-                fill_from(cid)
-        else:
-            # destination regions are disjoint per chunk, so concurrent
-            # fills never overlap; store get + codec decode release the GIL
-            list(pool.map(fill_from, cids))
-        if squeeze_axes:
-            out = np.squeeze(out, axis=tuple(squeeze_axes))
+                copy(cid)
+            return out
+        caller = threading.get_ident()
+
+        def fill_from(cid) -> None:
+            t0 = time.perf_counter()
+            copy(cid)
+            obs.record("store.read.inline" if threading.get_ident() == caller
+                       else "store.read.pooled", time.perf_counter() - t0)
+
+        self._session.read_chunks(self.path, cids, fill_from)
         return out
 
     def read(self) -> np.ndarray:

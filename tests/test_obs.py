@@ -251,10 +251,13 @@ def test_served_product_dispatches_one_kernel_call(catalog, served, kind):
     assert w.spans["product.compute"]["n"] == 1
     assert w.spans["product.encode"]["n"] == 1
     assert w.spans["http.request"]["n"] == 1
-    # the layers inside the computation take no more than it does
+    # the layers inside the computation, timed on its thread, take no
+    # more than it does (a multi-chunk read's get, decode and assemble
+    # spans may run on the read pool's threads, and sum thread-seconds:
+    # ``store.read`` is the read's wall time on this one)
+    assert w.spans["store.read"]["n"] >= 1
     inner = sum(w.spans[n]["s"] for n in ("dispatch.call", "dispatch.wait",
-                                          "store.get", "store.decode",
-                                          "store.assemble") if n in w.spans)
+                                          "store.read"))
     assert inner <= w.spans["product.compute"]["s"]
 
 
