@@ -45,8 +45,11 @@ def _exercise_store() -> None:
     the prefetch pipeline (``_inflight`` / ``_prefetch_hot`` /
     ``_prefetch_hits``), the simulated-latency backend's counters,
     ``cache_stats`` reads, and ``close`` — including two concurrent
-    readers so the locksets are observed under real contention."""
-    from repro.store import ObjectStore, Repository, SimulatedLatencyStore
+    readers so the locksets are observed under real contention.  Their
+    32 MiB reads take the output arena (``_blocks`` / ``_in_use``): one
+    made here, since the process's own was made with tracing off."""
+    from repro.store import (ObjectStore, Repository, SimulatedLatencyStore,
+                             outbuf)
 
     root = tempfile.mkdtemp(prefix="repro-tsan-agree-")
     try:
@@ -54,12 +57,17 @@ def _exercise_store() -> None:
         tx = repo.writable_session()
         tx.create_array("x", shape=(8,), dtype="float32",
                         chunks=(4,)).write_full(np.arange(8, dtype="float32"))
+        # the smallest output the arena takes, in two chunks
+        big = (2, outbuf.MIN_BYTES // 8)
+        tx.create_array("big", shape=big, dtype="float32",
+                        chunks=(1, big[1])).write_full(np.ones(big, "float32"))
         tx.commit("seed")
 
         # reopen over the simulated-latency backend (sleepless) so its
         # request counters and the prefetch pipeline are both observed
         sim = SimulatedLatencyStore(ObjectStore(f"{root}/repo"), sleep=False)
         s = Repository.open(sim).readonly_session(read_workers=2)
+        arena, outbuf._ARENA = outbuf._ARENA, outbuf.OutputArena()
         try:
             s.reader_pool()
             s.prefetch(["x"], wait=True)    # _inflight / _prefetch_hot
@@ -67,6 +75,7 @@ def _exercise_store() -> None:
             def read() -> None:
                 np.testing.assert_array_equal(
                     s.array("x").read(), np.arange(8, dtype="float32"))
+                assert (s.array("big").read() == 1).all()
 
             threads = [threading.Thread(target=read, name=f"agree-r{i}")
                        for i in range(2)]
@@ -75,11 +84,13 @@ def _exercise_store() -> None:
             for t in threads:
                 t.join()
             s.array("x").read()     # warm-cache hit path
+            s.array("big").read()   # an idle block filled again
             s.cache_stats()         # includes prefetch-hit counters
             sim.stats()
             sim.reset_stats()
         finally:
             s.close()
+            outbuf._ARENA = arena
     finally:
         shutil.rmtree(root, ignore_errors=True)
 

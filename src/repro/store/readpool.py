@@ -81,6 +81,9 @@ class _WorkList:
                     note_write(self, "_tasks", owner="_WorkList")
                     self._tasks.clear()
             finally:
+                # let go of the task, and of the read's output it holds,
+                # before the caller can learn that the list is done
+                task = None
                 with self._lock:
                     note_write(self, "_active", owner="_WorkList")
                     self._active -= 1

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro import obs
 
+from . import outbuf
 from .chunks import (ChunkGrid, normalize_selection, predicate_mask,
                      selection_bounds)
 from .codecs import default_codec
@@ -187,14 +188,15 @@ class Array:
 
     def _assemble(self, cids, bounds, out_shape) -> np.ndarray:
         """The output of a read: the fill value, then each chunk copied
-        into its region.  Several chunks go through
-        :meth:`Session.read_chunks`, which may copy some on helper
-        threads; each copy is counted as ``store.read.pooled`` there and
-        ``store.read.inline`` on this thread."""
+        into its region.  A large output is filled in a block an earlier
+        read mapped (:mod:`repro.store.outbuf`).  Several chunks go
+        through :meth:`Session.read_chunks`, which may copy some on
+        helper threads; each copy is counted as ``store.read.pooled``
+        there and ``store.read.inline`` on this thread."""
         # the output buffer and each chunk's copy into it: the span
         # ``store.assemble``, whose bytes are the output's
         with obs.span("store.assemble") as sp:
-            out = np.full(out_shape, self.meta.fill_value, dtype=self.dtype)
+            out = outbuf.full(out_shape, self.meta.fill_value, self.dtype)
             sp.nbytes = out.nbytes
         grid = self.meta.grid
 
